@@ -70,23 +70,19 @@ PERF_JSON = "BENCH_perf.json"
 def machine_info() -> dict:
     import os
 
-    info = {
+    import jax
+
+    return {
         "platform": platform.platform(),
         "python": platform.python_version(),
         "processor": platform.processor(),
         "cpu_count": os.cpu_count(),
-    }
-    try:
-        import jax
-
-        info["jax"] = {
+        "jax": {
             "version": jax.__version__,
             "backend": jax.default_backend(),
             "devices": [str(d) for d in jax.devices()],
-        }
-    except Exception as e:  # noqa: BLE001 - record why jax is absent
-        info["jax"] = {"unavailable": str(e)}
-    return info
+        },
+    }
 
 
 def _trajectory(sections: dict) -> dict:

@@ -298,9 +298,6 @@ def jax_parity(report=print) -> dict:
     (the jax engine runs float64 — observed agreement is ~1e-15)."""
     from repro.sim import jax_backend
 
-    if not jax_backend.have_jax():
-        report("jax parity: jax unavailable (FAIL)")
-        return {"available": False, "ok": False}
     worst = 0.0
     n_checked = 0
     for app in apps.iter_apps():
@@ -317,7 +314,7 @@ def jax_parity(report=print) -> dict:
     report(f"jax parity (paper cluster): {n_checked} placements x "
            f"fold on/off, max rel |jax - numpy| = {worst:.3e} "
            f"({'OK' if ok else 'FAIL'} @ {JAX_PARITY_RTOL:g})")
-    return {"available": True, "placements": n_checked,
+    return {"placements": n_checked,
             "max_rel_diff": worst, "rtol": JAX_PARITY_RTOL, "ok": ok}
 
 
@@ -350,9 +347,6 @@ def jax_bench(report=print, procs: int = JAX_SWEEP_PROCS,
     ``JAX_SPEEDUP_FLOOR``."""
     from repro.sim import jax_backend
 
-    if not jax_backend.have_jax():
-        report("jax bench: jax unavailable (FAIL)")
-        return {"available": False, "ok": False}
     rng = np.random.default_rng(0)
     work = []
     for app in apps.iter_apps():
@@ -409,7 +403,7 @@ def jax_bench(report=print, procs: int = JAX_SWEEP_PROCS,
     report(f"aggregate: numpy {tot_np * 1e3:.1f}ms  jax {tot_jax * 1e3:.1f}ms "
            f" speedup {speedup:.2f}x (floor {JAX_SPEEDUP_FLOOR:.0f}x)  "
            f"max rel diff {worst:.2e} ({'OK' if ok else 'FAIL'})")
-    return {"available": True, "procs": procs, "cands_per_app": n_cands,
+    return {"procs": procs, "cands_per_app": n_cands,
             "reps": reps, "apps": rows,
             "numpy_s": tot_np, "jax_s": tot_jax, "speedup": speedup,
             "speedup_floor": JAX_SPEEDUP_FLOOR, "max_rel_diff": worst,
@@ -476,10 +470,6 @@ def pipeline_bench(report=print, procs: int = PIPELINE_PROCS,
     ``PIPELINE_SPEEDUP_FLOOR``; values must match the synchronous path
     bit for bit."""
     from repro.sim import jax_backend
-
-    if not jax_backend.have_jax():
-        report("pipeline bench: jax unavailable (FAIL)")
-        return {"available": False, "ok": False}
 
     def expand(stacks, shape, device):
         """The tuner's per-group producer work, faithfully: canonical
@@ -563,7 +553,7 @@ def pipeline_bench(report=print, procs: int = PIPELINE_PROCS,
            f"{tot_pipe * 1e3:.1f}ms  speedup {speedup:.2f}x "
            f"(floor {PIPELINE_SPEEDUP_FLOOR:.1f}x)  values match: {match} "
            f"({'OK' if ok else 'FAIL'})")
-    return {"available": True, "procs": procs, "groups": n_groups,
+    return {"procs": procs, "groups": n_groups,
             "rows": rows, "reps": reps, "emulated_device": True,
             "apps": app_rows, "sync_s": tot_sync, "pipe_s": tot_pipe,
             "speedup": speedup, "speedup_floor": PIPELINE_SPEEDUP_FLOOR,
@@ -848,41 +838,29 @@ def check(result: dict) -> list[str]:
                       f"{parity['max_abs_diff_s']:.3e}s "
                       f"(> {ENGINE_ATOL:g})")
     jp = result.get("jax_parity")
-    if jp is not None:
-        if not jp.get("available", False):
-            errors.append("the jax backend is unavailable (the parity lane "
-                          "requires jax)")
-        elif not jp["ok"]:
-            errors.append(f"jax engine diverged from the numpy engine by "
-                          f"{jp['max_rel_diff']:.3e} relative "
-                          f"(> {JAX_PARITY_RTOL:g})")
+    if jp is not None and not jp["ok"]:
+        errors.append(f"jax engine diverged from the numpy engine by "
+                      f"{jp['max_rel_diff']:.3e} relative "
+                      f"(> {JAX_PARITY_RTOL:g})")
     jb = result.get("jax_bench")
     if jb is not None:
-        if not jb.get("available", False):
-            errors.append("the jax backend is unavailable (the speedup lane "
-                          "requires jax)")
-        else:
-            if jb["speedup"] < jb["speedup_floor"]:
-                errors.append(
-                    f"jax beam-pricing speedup {jb['speedup']:.2f}x fell "
-                    f"below the committed {jb['speedup_floor']:.0f}x floor")
-            if jb["max_rel_diff"] > jb["rtol"]:
-                errors.append(f"jax sweep diverged by "
-                              f"{jb['max_rel_diff']:.3e} relative "
-                              f"(> {jb['rtol']:g})")
+        if jb["speedup"] < jb["speedup_floor"]:
+            errors.append(
+                f"jax beam-pricing speedup {jb['speedup']:.2f}x fell "
+                f"below the committed {jb['speedup_floor']:.0f}x floor")
+        if jb["max_rel_diff"] > jb["rtol"]:
+            errors.append(f"jax sweep diverged by "
+                          f"{jb['max_rel_diff']:.3e} relative "
+                          f"(> {jb['rtol']:g})")
     pb = result.get("pipeline_bench")
     if pb is not None:
-        if not pb.get("available", False):
-            errors.append("the jax backend is unavailable (the pipeline "
-                          "lane requires jax)")
-        else:
-            if pb["speedup"] < pb["speedup_floor"]:
-                errors.append(
-                    f"pipelined Phase 3 speedup {pb['speedup']:.2f}x fell "
-                    f"below the committed {pb['speedup_floor']:.1f}x floor")
-            if not pb["values_match"]:
-                errors.append("the pipelined Phase 3 returned different "
-                              "step times than the synchronous path")
+        if pb["speedup"] < pb["speedup_floor"]:
+            errors.append(
+                f"pipelined Phase 3 speedup {pb['speedup']:.2f}x fell "
+                f"below the committed {pb['speedup_floor']:.1f}x floor")
+        if not pb["values_match"]:
+            errors.append("the pipelined Phase 3 returned different "
+                          "step times than the synchronous path")
     cb = result.get("cache_bench")
     if cb is not None:
         if cb["speedup"] < cb["speedup_floor"]:

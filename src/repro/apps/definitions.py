@@ -56,6 +56,7 @@ PENNANT_ZONES = (2048, 16384)
 PENNANT_FIELDS = 3          # p, u, v halos exchanged per hydro step
 CIRCUIT_NODES_PER_PIECE = 64
 CIRCUIT_WIRES_PER_PIECE = 96
+CIRCUIT_PIECES = 16384      # ~1.5M wires in the executed graph
 
 
 def _matmul_machine(procs: int) -> tuple[int, int]:
@@ -563,7 +564,9 @@ register(Application(
     collective=CIRCUIT_PATTERN,
     lowlevel_fixture="benchmarks/lowlevel/circuit_raw.py",
     validate="circuit",
-    meta={"nodes_per_piece": CIRCUIT_NODES_PER_PIECE},
+    meta={"nodes_per_piece": CIRCUIT_NODES_PER_PIECE,
+          "wires_per_piece": CIRCUIT_WIRES_PER_PIECE,
+          "pieces": CIRCUIT_PIECES},
 ))
 
 register(Application(

@@ -12,9 +12,15 @@ Runs every selected app through the full pipeline —
     PYTHONPATH=src python -m repro.apps.run --all --tune      # autotuner
     PYTHONPATH=src python -m repro.apps.run --all --simulate  # sim timeline
 
-``--execute`` additionally runs each app's distributed kernel on fake CPU
-devices and checks it against its single-device reference (the flag must
-set XLA_FLAGS before JAX initializes, so use it from a fresh process).
+``--execute`` additionally runs each app's distributed kernel at the
+registry's problem sizes on the first ``--procs`` devices JAX reports (the
+chip's, or fake CPU devices when the caller sets ``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and checks it
+against its single-device reference under a stated bound. An app whose
+grid policy forbids the count is skipped; any other app that cannot run
+fails the command:
+
+    PYTHONPATH=src python -m repro.apps.run --all --execute --procs 1
 
 ``--tune`` runs the mapper autotuner (``repro.search``) over each selected
 app's declared search space: candidates are scored with the app's cost
@@ -34,8 +40,8 @@ NumPy reference (same winners, <=1e-6-relative identical seconds):
 streaming producer/consumer Phase 3 on or off (default: auto — stream
 when the pricing engine is ``batched-jax``; identical numbers either
 way). ``--cache-dir DIR`` persists placement prices under
-``DIR/prices`` (and, under ``--backend jax``, XLA compiles under
-``DIR/xla``) so re-tunes serve from disk:
+``DIR/prices`` so re-tunes serve from disk (compiled programs go to JAX's
+persistent cache, see ``repro.runtime.compile_cache``):
 
     PYTHONPATH=src python -m repro.apps.run --all --tune --time \\
         --backend jax --pipeline --cache-dir ~/.cache/repro-tune
@@ -129,8 +135,8 @@ def tune(selection, procs: int | None, report=print,
     docs/simulator.md "Backends"). ``pipeline`` forces Phase 3's
     streaming producer/consumer shape on (True) or off (False; None
     auto-selects it for the JAX engine), and ``cache_dir`` points the
-    persistent price cache + JAX compilation cache at a directory so
-    repeat tunes skip pricing and XLA compiles across processes.
+    persistent price cache at a directory so repeat tunes skip pricing
+    across processes.
     ``warm_start_from`` points at a plan-cache directory (the tuning
     service's ``--cache-dir``, same on-disk format): cached winners near
     each requested scale seed the beam, and every winner tuned here is
@@ -161,20 +167,12 @@ def tune(selection, procs: int | None, report=print,
         plan_cache = PlanCache(os.path.join(warm_start_from, "plans"))
         report(f"plan cache: {plan_cache.root}")
     if time_domain and backend == "jax":
-        from repro.sim.jax_backend import enable_compilation_cache, \
-            platform_info
+        from repro.sim.jax_backend import platform_info
 
-        if cache_dir is not None:
-            enable_compilation_cache(os.path.join(cache_dir, "xla"))
         info = platform_info()
         devices = ",".join(info["devices"]) or "-"
         report(f"jax backend: platform={info['platform']} "
                f"devices={info['device_count']}x[{devices}]")
-        if info["pallas_interpret"]:
-            report("warning: JAX resolved to CPU — the Pallas kernel "
-                   "path would run in interpret mode (slow); pricing "
-                   "uses the plain XLA jit here, and accelerator-grade "
-                   "throughput needs a TPU/GPU runtime")
 
     failures = []
     tuned = 0
@@ -302,6 +300,46 @@ def simulate(selection, procs: int | None, report=print,
                    json_path, report)
 
 
+def execute(selection, procs: int | None, report=print,
+            json_path: str | None = None) -> int:
+    """Run each app's kernel against its reference (``repro.apps
+    .validate``); nonzero when any app that its grid policy admits at
+    this count failed or could not run."""
+    from repro.apps import validate
+
+    report(f"{'app':10s} {'procs':>5s} {'grid':>8s} {'devices':>7s} "
+           f"{'device':>14s} {'max_err':>9s} {'bound':>7s} {'ok':>5s} "
+           f"{'wall_s':>7s}")
+    rows, failures = [], []
+    for app in selection:
+        n = app.procs(procs)
+        try:
+            app.tile_grid(n)
+        except ValueError as e:
+            report(f"{app.name:10s} {n:5d} skip: {e}")
+            continue
+        try:
+            res = validate.run(app, n)
+        except Exception as e:  # noqa: BLE001 - reported, fails the run
+            failures.append(f"{app.name}: {type(e).__name__}: {e}")
+            report(f"{app.name:10s} {n:5d} FAILED: {e}")
+            continue
+        grid = "x".join(str(g) for g in res["grid"])
+        report(f"{app.name:10s} {n:5d} {grid:>8s} "
+               f"{res['distinct_devices']:7d} {res['device']:>14s} "
+               f"{res['max_err']:9.2e} {res['bound']:7.0e} "
+               f"{str(res['ok']):>5s} {res['wall_s']:7.2f}")
+        rows.append({"app": app.name, "procs": n, **res})
+        if not res["ok"]:
+            failures.append(
+                f"{app.name}: max_err {res['max_err']:.3e} vs bound "
+                f"{res['bound']:g} on {res['distinct_devices']} distinct "
+                f"device(s)")
+    if not rows and not failures:
+        failures.append("no selected app admits this processor count")
+    return _finish(procs, rows, failures, json_path, report)
+
+
 def report_table(rows, report=print) -> None:
     report(
         f"{'app':10s} {'procs':>5s} {'grid':>12s} {'mapple':>7s} "
@@ -339,8 +377,8 @@ def main(argv=None) -> int:
     ap.add_argument("--procs", type=int, default=None,
                     help="processor count (default: per-app paper scale)")
     ap.add_argument("--execute", action="store_true",
-                    help="also run each kernel vs its reference on fake "
-                         "CPU devices")
+                    help="run each app's kernel at the registry's sizes "
+                         "on the first --procs devices vs its reference")
     ap.add_argument("--show-ir", action="store_true",
                     help="print each mapper's recorded transformation IR "
                          "(the inspectable ProcSpace op programs)")
@@ -367,9 +405,8 @@ def main(argv=None) -> int:
                          "pricing sweep)")
     ap.add_argument("--cache-dir", default=None, metavar="DIR",
                     help="with --tune --time: persistent cache directory "
-                         "— priced placements (DIR/prices) and, with "
-                         "--backend jax, compiled XLA programs (DIR/xla) "
-                         "are reused across processes")
+                         "— priced placements (DIR/prices) are reused "
+                         "across processes")
     ap.add_argument("--warm-start-from", default=None, metavar="DIR",
                     help="with --tune --time: seed the beam from the plan "
                          "cache under DIR/plans (the tuning service's "
@@ -379,8 +416,9 @@ def main(argv=None) -> int:
                     help="run each app's mapped step through the "
                          "discrete-event simulator and print the timeline")
     ap.add_argument("--json", default=None, metavar="PATH",
-                    help="with --tune/--simulate: write machine-readable "
-                         "results (leaderboard + winner IR / timelines)")
+                    help="with --tune/--simulate/--execute: write "
+                         "machine-readable results (leaderboard + winner "
+                         "IR / timelines / errors against the reference)")
     ap.add_argument("--list", action="store_true",
                     help="list registered applications")
     args = ap.parse_args(argv)
@@ -400,30 +438,18 @@ def main(argv=None) -> int:
         ap.error("--cache-dir requires --tune --time")
     if args.warm_start_from is not None and not args.time:
         ap.error("--warm-start-from requires --tune --time")
-    if args.backend == "jax":
-        from repro.sim.jax_backend import have_jax
-
-        if not have_jax():
-            ap.error("--backend jax needs jax installed in this "
-                     "environment; use --backend numpy")
     if args.simulate and (args.execute or args.show_ir):
         ap.error("--simulate is a separate mode; run it without "
                  "--execute/--show-ir")
-    if args.json and not (args.tune or args.simulate):
-        ap.error("--json requires --tune or --simulate")
-
-    if args.execute:
-        # Must happen before JAX initializes its backends. Append to any
-        # existing XLA_FLAGS rather than silently losing the device count.
-        count = args.procs or 8
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={count}"
-            ).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    if args.execute and args.show_ir:
+        ap.error("--execute is a separate mode; run it without --show-ir")
+    if args.json and not (args.tune or args.simulate or args.execute):
+        ap.error("--json requires --tune, --simulate or --execute")
 
     from repro import apps
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.list:
         for app in apps.iter_apps():
@@ -449,6 +475,8 @@ def main(argv=None) -> int:
                     warm_start_from=args.warm_start_from)
     if args.simulate:
         return simulate(selection, args.procs, json_path=args.json)
+    if args.execute:
+        return execute(selection, args.procs, json_path=args.json)
 
     rows = [analyze(app, args.procs) for app in selection]
     report_table(rows)
@@ -463,30 +491,6 @@ def main(argv=None) -> int:
     if not all(r["bijective"] for r in rows):
         print("ERROR: non-bijective mapping produced", file=sys.stderr)
         return 1
-
-    if args.execute:
-        from repro.apps import validate
-
-        print(f"\n{'app':10s} {'procs':>5s} {'max_err':>10s} {'ok':>4s}")
-        failed, ran = [], 0
-        for app, row in zip(selection, rows):
-            try:
-                res = validate.run(app, row["procs"])
-                ran += 1
-                print(f"{app.name:10s} {row['procs']:5d} "
-                      f"{res['max_err']:10.2e} {str(res['ok']):>4s}")
-                if not res["ok"]:
-                    failed.append(app.name)
-            except validate.SkipValidation as e:
-                print(f"{app.name:10s} {row['procs']:5d} {'—':>10s}  "
-                      f"skip: {e}")
-        if failed:
-            print(f"ERROR: numeric check failed: {failed}", file=sys.stderr)
-            return 1
-        if not ran:
-            print("ERROR: --execute validated nothing (no app had enough "
-                  "devices)", file=sys.stderr)
-            return 1
     return 0
 
 

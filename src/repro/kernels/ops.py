@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU backends (this container) the kernels execute in interpret mode —
-the kernel body runs in Python for correctness validation; on TPU they
-lower to Mosaic. Model code calls these through ``use_pallas=True``.
+On the CPU backend the kernels execute in interpret mode — the kernel body
+runs in Python for correctness validation; on every other backend they
+are compiled (on TPU they lower to Mosaic). Model code calls these
+through ``use_pallas=True``.
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from repro.kernels import wkv6 as wkv_mod
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
@@ -50,7 +51,7 @@ def flash_attention(q, k, v, *, window: int = 0, scale=None,
 
 
 @functools.partial(jax.jit, static_argnames=("bm",))
-def stencil_step(field, bm: int = st_mod.DEFAULT_BM):
+def stencil_step(field, bm: int | None = None):
     return st_mod.stencil_pallas(field, bm=bm, interpret=_interpret())
 
 

@@ -14,14 +14,20 @@ Both are one kernel: ``out[r] = max_j sum_{i<seg} vals[r, j*seg + i]``.
 
 Tiling: grid (rows/br, cols/bc) with the column axis fastest; each block
 reduces its (br, bc) tile to per-row partial maxima accumulated in VMEM
-across the column sweep (``bc`` is always a multiple of ``seg``, so no
-segment straddles a block boundary). Values are assumed non-negative
-(they are message counts and byte loads): the wrapper zero-pads ragged
-shapes, and a zero pad segment is exactly an idle port.
+across the column sweep. The tiling follows the TPU's (8, 128) rule: ``br``
+is a multiple of 8, ``bc`` a multiple of both 128 and ``seg`` (so no
+segment straddles a block boundary), and the per-row result is kept
+lane-dense as a (br, 128) block whose lanes all hold the row's value.
+Segment sums are formed in-register by lane rotations (``log2(seg)`` of
+them), and only the lanes that start a segment enter the max. Values are
+assumed non-negative (they are message counts and byte loads): the
+wrapper zero-pads ragged shapes, and a zero pad segment is exactly an
+idle port. Mosaic has no float64, so the kernel takes float32 on the chip.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BR = 8
 DEFAULT_BC = 512
+_SUBLANES = 8
+_LANES = 128
+
+
+def _window_sums(blk: jax.Array, seg: int) -> jax.Array:
+    """``out[:, c] = sum_{i<seg} blk[:, c + i]`` (lanes wrap; the lanes
+    that start an aligned segment never read a wrapped lane)."""
+    bc = blk.shape[1]
+
+    def ahead(x, k):                     # x[:, c + k]
+        return pltpu.roll(x, bc - k, 1)
+
+    # ``span`` holds sums of ``width`` consecutive lanes; the binary digits
+    # of ``seg`` pick which spans, at which offsets, make up the window.
+    out, span, width, off, rest = None, blk, 1, 0, seg
+    while rest:
+        if rest & 1:
+            term = span if off == 0 else ahead(span, off)
+            out = term if out is None else out + term
+            off += width
+        rest >>= 1
+        if rest:
+            span = span + ahead(span, width)
+            width *= 2
+    return out
 
 
 def _segment_rowmax_kernel(v_ref, o_ref, acc_ref, *, seg: int, n_c: int):
@@ -40,8 +71,10 @@ def _segment_rowmax_kernel(v_ref, o_ref, acc_ref, *, seg: int, n_c: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     blk = v_ref[...]
-    br, bc = blk.shape
-    part = blk.reshape(br, bc // seg, seg).sum(axis=2).max(axis=1)
+    if seg > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+        blk = jnp.where(lane % seg == 0, _window_sums(blk, seg), 0)
+    part = blk.max(axis=1, keepdims=True)               # (br, 1)
     acc_ref[...] = jnp.maximum(acc_ref[...], part)
 
     @pl.when(j == n_c - 1)
@@ -59,14 +92,19 @@ def segment_rowmax_pallas(
 ) -> jax.Array:
     """``max_j sum_{i<seg} vals[r, j*seg + i]`` per row, for ``vals >= 0``.
 
-    Ragged shapes are zero-padded up to the block tiling (a zero pad
+    ``br``/``bc`` are rounded to the tiling the TPU accepts (see the
+    module docstring), and the table is zero-padded up to it (a zero pad
     segment behaves as an idle port under the non-negative contract).
     """
     rows, cols = vals.shape
     seg = int(seg)
     assert seg >= 1 and cols % seg == 0, (vals.shape, seg)
-    bc = seg * max(1, min(bc, cols) // seg)
-    br = min(br, rows)
+    if vals.dtype == jnp.float64 and not interpret:
+        raise ValueError("segment_rowmax on the TPU takes float32: Mosaic "
+                         "has no float64")
+    unit = math.lcm(seg, _LANES)
+    bc = unit * max(1, min(bc, cols) // unit)
+    br = _SUBLANES * max(1, br // _SUBLANES)
     pad_r = -rows % br
     pad_c = -cols % bc
     if pad_r or pad_c:
@@ -76,9 +114,9 @@ def segment_rowmax_pallas(
         functools.partial(_segment_rowmax_kernel, seg=seg, n_c=grid[1]),
         grid=grid,
         in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((br,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((vals.shape[0],), vals.dtype),
-        scratch_shapes=[pltpu.VMEM((br,), vals.dtype)],
+        out_specs=pl.BlockSpec((br, _LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((vals.shape[0], _LANES), vals.dtype),
+        scratch_shapes=[pltpu.VMEM((br, _LANES), vals.dtype)],
         interpret=interpret,
     )(vals)
-    return out[:rows]
+    return out[:rows, 0]

@@ -13,7 +13,9 @@ from the (batch*head) grid axis — on real TPUs, from Megacore + multiple
 chips via shard_map over heads.
 
 Layout: r/k/v/w (BH, T, N) fp32; u (BH, N); outputs y (BH, T, N) and the
-final state (BH, N, N).
+final state (BH, N, N). ``u`` enters the kernel as (BH, 1, N) so that its
+(1, 1, N) block spans the array's last two dimensions, as the TPU's
+(8, 128) block rule requires.
 """
 from __future__ import annotations
 
@@ -35,7 +37,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0]                                        # (N,)
+    u = u_ref[0, 0]                                     # (N,)
 
     def step(t, _):
         r_t = r_ref[0, t]                               # (N,)
@@ -74,7 +76,7 @@ def wkv6_pallas(
             pl.BlockSpec((1, bt, N), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, bt, N), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, bt, N), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, N), lambda b, t: (b, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bt, N), lambda b, t: (b, t, 0)),
@@ -86,5 +88,5 @@ def wkv6_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(BH, 1, N))
     return y, s_out

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: the 16x16
@@ -8,7 +5,10 @@ single-pod mesh and the 2x16x16 multi-pod mesh must compile for every
 assigned architecture and input shape, and the compiled artifacts yield
 the memory/cost/collective numbers EXPERIMENTS.md reports.
 
-Usage:
+The meshes need 512 devices; on a host, ask XLA for that many fake CPU
+devices before JAX starts:
+
+  export JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=512
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both \
       --out results/dryrun.json

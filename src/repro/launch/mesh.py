@@ -27,8 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False, devices=None,
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} — run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py "
-            "sets this automatically)"
+            "XLA_FLAGS=--xla_force_host_platform_device_count=512"
         )
     devices = list(devices)[:n]
     if permutation is not None:

@@ -2,9 +2,13 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
       --batch 4 --prompt-len 32 --gen 16
+  PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
+      --scale full --batch 4 --prompt-len 128 --gen 32
 
-Reduced configs on CPU; same code path drives the full configs on a pod
-(dryrun.py proves those compile).
+Each of the ``--batch`` rows is one request. ``--scale reduced`` serves a
+tiny same-family config (quick host checks); ``--scale full`` the
+published widths. The last line printed is a JSON summary (generated
+tokens, one-off wall times), which ``main`` also returns.
 """
 from __future__ import annotations
 
@@ -13,8 +17,10 @@ import json
 import time
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
+def main(argv=None) -> dict:
+    ap = argparse.ArgumentParser(
+        prog="python -m repro.launch.serve", description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -22,10 +28,14 @@ def main() -> None:
     ap.add_argument("--scale", default="reduced", choices=["reduced", "full"])
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.models import build
@@ -57,6 +67,7 @@ def main() -> None:
     for t in range(args.prompt_len):
         tok = prompt[:, t:t + 1]
         logits, cache = decode(params, cache, jnp.int32(t), tok)
+    jax.block_until_ready(logits)
     prefill_s = time.time() - t0
 
     # --- batched greedy/temperature decode
@@ -84,12 +95,19 @@ def main() -> None:
 
     tokens = jnp.stack(outs, axis=1)
     print("generated token ids (first row):", tokens[0].tolist())
-    print(json.dumps({
-        "arch": args.arch,
+    summary = {
+        "arch": args.arch, "scale": args.scale,
+        "device": jax.devices()[0].device_kind,
+        "requests": B,
+        "prompt_tokens": B * args.prompt_len,
+        "generated_tokens": int(tokens.size),
+        "logits_finite": bool(jnp.isfinite(logits).all()),
         "prefill_s": round(prefill_s, 3),
         "decode_s": round(decode_s, 3),
         "decode_tok_per_s": round(B * args.gen / max(decode_s, 1e-9), 1),
-    }))
+    }
+    print(json.dumps(summary))
+    return summary
 
 
 if __name__ == "__main__":

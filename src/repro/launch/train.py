@@ -1,11 +1,15 @@
 """Training launcher: --arch <id> [--steps N] [--scale reduced|full].
 
-On this CPU container it trains the REDUCED config end-to-end (the full
-configs are exercised by dryrun.py); on a real pod the same driver runs the
-full config over the production mesh with the same code path:
+``--scale reduced`` trains a tiny same-family config (quick host checks);
+``--scale full`` trains the published widths on the default device:
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
-      --steps 200 --batch 16 --seq 64 --ckpt-dir /tmp/ckpt
+      --steps 200 --batch 16 --seq 64 --ckpt-dir ckpt
+  PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
+      --scale full --steps 3 --batch 4 --seq 2048
+
+The last line printed is a JSON summary (losses, steps, one-off wall time),
+which ``main`` also returns.
 """
 from __future__ import annotations
 
@@ -14,8 +18,10 @@ import json
 import time
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
+def main(argv=None) -> dict:
+    ap = argparse.ArgumentParser(
+        prog="python -m repro.launch.train", description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=16)
@@ -31,9 +37,13 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a simulated failure at this step (demo)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.checkpoint import CheckpointManager
     from repro.configs import get_config
@@ -101,11 +111,16 @@ def main() -> None:
     dt = time.time() - t0
     if mgr is not None:
         mgr.wait()
-    print(json.dumps({
+    summary = {
+        "arch": args.arch, "scale": args.scale,
+        "device": jax.devices()[0].device_kind,
+        "losses": [h["loss"] for h in hist],
         "first_loss": hist[0]["loss"], "last_loss": hist[-1]["loss"],
         "steps": len(hist), "wall_s": round(dt, 1),
         "steps_per_s": round(len(hist) / dt, 2),
-    }))
+    }
+    print(json.dumps(summary))
+    return summary
 
 
 if __name__ == "__main__":

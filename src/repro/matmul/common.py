@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.mapper import Mapper
 from repro.core.translate import mesh_from_mapper
-from repro.core.jaxcompat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,7 +107,7 @@ def sharded_matmul_wrapper(
     check_vma: bool = False,
 ):
     """Wrap an algorithm body in shard_map + jit over the grid's mesh."""
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=grid.mesh, in_specs=in_specs, out_specs=out_spec,
         check_vma=check_vma,
     )

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.params import ParamDef, normal_init, ones_init, scaled_init, zeros_init
-from repro.core.jaxcompat import shard_map
 
 # Above this sequence length attention always takes the online-softmax
 # chunked path: a naive (B,H,S,S) fp32 score tensor at S=4096 with
@@ -176,7 +175,7 @@ def sp_attention(q, k, v, *, window: int = 0, scale: float | None = None):
         )
 
     spec = P(batch_axes if batch_axes else None, "model", None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -273,7 +272,7 @@ def sp_decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     bspec = batch_axes if batch_axes else None
     q_spec = P(bspec, None, None, None)
     kv_spec = P(bspec, "model", None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec, check_vma=False,
     )(q, k_cache, v_cache)

@@ -17,7 +17,6 @@ from repro.models.config import ModelConfig
 from repro.models.params import ParamDef, normal_init
 from repro.models.sharding import constrain
 from repro.models import layers
-from repro.core.jaxcompat import shard_map
 
 CAPACITY_FACTOR = 1.25
 
@@ -156,7 +155,7 @@ def _moe_shard_map(params, x, cfg: ModelConfig, mesh, batch_axes, ep, dp):
     x_spec = P(batch_axes if batch_axes else None, "model", None)
     router_spec = P(None, None)
     w_spec = P("model", None, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, router_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),

@@ -26,7 +26,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.mapper import block_mapper
 from repro.core.pspace import ProcSpace
 from repro.matmul.common import MatmulGrid, build_grid
-from repro.core.jaxcompat import shard_map
 
 AXES = ("x",)
 
@@ -62,17 +61,15 @@ class CircuitState:
 def generate(cfg: CircuitConfig, seed: int = 0) -> CircuitState:
     rng = np.random.default_rng(seed)
     n, w = cfg.n_nodes, cfg.n_wires
-    src = np.empty(w, np.int32)
-    dst = np.empty(w, np.int32)
-    for p in range(cfg.pieces):
-        lo = p * cfg.nodes_per_piece
-        for i in range(cfg.wires_per_piece):
-            wi = p * cfg.wires_per_piece + i
-            src[wi] = lo + rng.integers(cfg.nodes_per_piece)
-            if rng.random() < cfg.pct_internal:
-                dst[wi] = lo + rng.integers(cfg.nodes_per_piece)
-            else:
-                dst[wi] = rng.integers(n)
+    # Wire i belongs to piece i // wires_per_piece and is sourced there;
+    # its other end stays in the piece with probability pct_internal.
+    lo = np.repeat(np.arange(cfg.pieces) * cfg.nodes_per_piece,
+                   cfg.wires_per_piece)
+    src = lo + rng.integers(cfg.nodes_per_piece, size=w)
+    internal = rng.random(w) < cfg.pct_internal
+    dst = np.where(internal, lo + rng.integers(cfg.nodes_per_piece, size=w),
+                   rng.integers(n, size=w))
+    src, dst = src.astype(np.int32), dst.astype(np.int32)
     return CircuitState(
         voltage=jnp.asarray(rng.normal(size=n).astype(np.float32)),
         charge=jnp.zeros(n, jnp.float32),
@@ -115,7 +112,7 @@ def circuit_body(cfg: CircuitConfig, n_pieces: int):
 
 
 def run(state: CircuitState, grid: MatmulGrid, cfg: CircuitConfig) -> jax.Array:
-    fn = shard_map(
+    fn = jax.shard_map(
         circuit_body(cfg, grid.shape[0]),
         mesh=grid.mesh,
         in_specs=(P("x"), P("x"), P("x"), P("x"), P("x"), P("x")),

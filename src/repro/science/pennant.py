@@ -23,7 +23,6 @@ from repro.core.decompose import cached_optimal
 from repro.core.mapper import block_mapper
 from repro.core.pspace import ProcSpace
 from repro.matmul.common import MatmulGrid, build_grid
-from repro.core.jaxcompat import shard_map
 
 AXES = ("x", "y")
 GAMMA = 1.4
@@ -120,7 +119,7 @@ def pennant_body(cfg: PennantConfig, grid_shape):
 
 
 def run(state, grid: MatmulGrid, cfg: PennantConfig):
-    fn = shard_map(
+    fn = jax.shard_map(
         pennant_body(cfg, grid.shape),
         mesh=grid.mesh,
         in_specs=(P("x", "y"),) * 4,

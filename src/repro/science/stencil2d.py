@@ -18,7 +18,6 @@ from repro.core.decompose import cached_optimal, greedy_factorization
 from repro.core.mapper import block_mapper
 from repro.core.pspace import ProcSpace
 from repro.matmul.common import build_grid, MatmulGrid
-from repro.core.jaxcompat import shard_map
 
 AXES = ("x", "y")
 
@@ -105,7 +104,7 @@ def stencil_body(grid_shape: tuple[int, int], cfg: StencilConfig):
 
 def run(field: jax.Array, grid: MatmulGrid, cfg: StencilConfig) -> jax.Array:
     body = stencil_body(grid.shape, cfg)  # type: ignore[arg-type]
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=grid.mesh, in_specs=(P("x", "y"),), out_specs=P("x", "y"),
         check_vma=False,
     )

@@ -102,6 +102,9 @@ def main(argv=None) -> int:
                     help="emit one JSON object per request instead of text")
     args = ap.parse_args(argv)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     requests = (load_trace(args.trace) if args.trace
                 else demo_trace(args.demo, args.seed))
     errors = 0

@@ -38,10 +38,11 @@ out-of-bounds drop masking.
 The per-level reduction of the dense mode is also available as a Pallas
 kernel (``repro.kernels.segment_reduce``, ``use_pallas=True``) — on CPU
 it runs in interpret mode as a correctness path, on TPU it lowers to
-Mosaic.
+Mosaic. Mosaic has no float64, so this path takes ``dtype="float32"``
+only.
 
-``dtype="float64"`` (the default, run under ``jax.experimental
-.enable_x64``) reproduces the NumPy reference to ~1e-15 relative — the
+``dtype="float64"`` (the default, run under ``jax.enable_x64``)
+reproduces the NumPy reference to ~1e-15 relative — the
 registry-wide <=1e-6 parity gate in ``benchmarks/sim_eval.py`` runs in
 float64. ``dtype="float32"`` halves bandwidth but accumulates port loads
 in single precision: expect ~1e-5 relative drift on large slabs, fine
@@ -72,14 +73,8 @@ from repro.sim.collectives import (
 )
 from repro.sim.topology import Topology
 
-try:  # pragma: no cover - exercised only where jax is absent
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-except Exception:  # noqa: BLE001 - any import failure means "no jax"
-    jax = None
-    jnp = None
-    enable_x64 = None
+import jax
+import jax.numpy as jnp
 
 #: Cell ceiling for the dense-gather mode's (n_unique x ntiles) lookup
 #: tables; schedules past it (or with repeated per-slab endpoints) use
@@ -93,49 +88,20 @@ _MAX_DEVICE_ELEMS = 1 << 24
 _DTYPES = ("float64", "float32")
 
 
-def have_jax() -> bool:
-    """True when the JAX backend can be constructed in this process."""
-    return jax is not None
-
-
 def platform_info() -> dict:
-    """What this process's JAX runtime resolved to: platform name, device
-    count and kinds, and whether the Pallas kernel would run in interpret
-    mode (it does on CPU — a correctness path, slower than the plain jit).
-    ``repro.apps.run --backend jax`` prints this so a CPU fallback is
-    never silent."""
-    if jax is None:
-        return {"available": False}
+    """What this process's JAX runtime resolved to: platform name and
+    device count and kinds. ``repro.apps.run --backend jax`` prints it
+    so the device the pricing ran on is always on record."""
     devices = jax.devices()
-    platform = jax.default_backend()
     return {
-        "available": True,
-        "platform": platform,
+        "platform": jax.default_backend(),
         "device_count": len(devices),
         "devices": [d.device_kind for d in devices],
-        "pallas_interpret": platform == "cpu",
     }
 
 
-def enable_compilation_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` so repeat
-    tunes in fresh processes skip XLA compilation entirely. Thresholds
-    are dropped to zero because this engine's programs are many and
-    individually quick to compile — exactly the population the default
-    min-compile-time filter would decline to cache."""
-    if jax is None:  # pragma: no cover - guarded by have_jax() upstream
-        return
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - knob absent on this jax version
-            pass
-
-
 def _x64(dtype: str):
-    return enable_x64() if dtype == "float64" else nullcontext()
+    return jax.enable_x64(True) if dtype == "float64" else nullcontext()
 
 
 def _pow2_floor(n: int) -> int:
@@ -519,14 +485,15 @@ class JaxBatchSimulator(BatchSimulator):
 
     def __post_init__(self) -> None:
         super().__post_init__()
-        if jax is None:
-            raise RuntimeError(
-                "the 'batched-jax' engine needs jax installed; use the "
-                "NumPy batch engine (engine='batched') instead"
-            )
         if self.dtype not in _DTYPES:
             raise ValueError(
                 f"dtype must be one of {_DTYPES}, got {self.dtype!r}"
+            )
+        if self.use_pallas and self.dtype == "float64":
+            raise ValueError(
+                "use_pallas=True needs dtype='float32': the Pallas "
+                "segment-reduce kernel lowers to Mosaic, which has no "
+                "float64"
             )
 
     def phase_durations(self, assignments: np.ndarray, *,
@@ -685,8 +652,6 @@ def jax_batch_simulator(pattern: CollectivePattern, spec: MachineSpec,
 
 __all__ = [
     "JaxBatchSimulator",
-    "enable_compilation_cache",
-    "have_jax",
     "jax_batch_simulator",
     "platform_info",
     "to_jax",

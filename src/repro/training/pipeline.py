@@ -27,7 +27,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core.jaxcompat import shard_map
 
 
 def split_stages(stacked_params: Any, n_stages: int) -> Any:
@@ -109,7 +108,7 @@ def pipelined_apply(
 
     # P(pod_axis) acts as a pytree prefix: dim 0 (the stage dim) of every
     # parameter leaf shards over the pod axis.
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(pod_axis), P()),
         out_specs=P(),

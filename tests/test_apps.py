@@ -156,8 +156,10 @@ def test_run_cli_all_analysis():
 
 
 @pytest.mark.slow
-def test_run_cli_execute_subprocess():
-    """Full numeric validation of all nine apps on fake devices."""
+def test_run_cli_execute_subprocess(tmp_path):
+    """Full numeric validation of all nine apps, at the registry's sizes,
+    on eight fake CPU devices that the caller (not the CLI) asks for."""
+    import json
     import os
     import subprocess
     import sys
@@ -167,10 +169,16 @@ def test_run_cli_execute_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(repo / "src")
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    out = tmp_path / "execute.json"
     proc = subprocess.run(
-        [sys.executable, "-m", "repro.apps.run", "--all", "--execute"],
+        [sys.executable, "-m", "repro.apps.run", "--all", "--execute",
+         "--json", str(out)],
         capture_output=True, text=True, timeout=600, env=env, cwd=str(repo),
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert proc.stdout.count("True") >= 9
+    rows = json.loads(out.read_text())["apps"]
+    assert len(rows) == 9
+    for row in rows:
+        assert row["ok"] and row["max_err"] <= row["bound"], row
+        assert row["distinct_devices"] == row["procs"], row

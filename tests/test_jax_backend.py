@@ -14,9 +14,6 @@ from repro.sim import jax_backend as jb
 from repro.sim.batch import price_stacks
 from repro.sim.cost import SimulatedTimeCostModel, time_search_space
 
-pytestmark = pytest.mark.skipif(not jb.have_jax(),
-                                reason="jax unavailable")
-
 # float64 (the default) reproduces the NumPy engine to round-off; the
 # registry parity gate in benchmarks/sim_eval.py runs at 1e-6 relative.
 F64_RTOL = 1e-12
@@ -80,12 +77,23 @@ def test_scatter_mode_parity(app_name, monkeypatch):
 
 
 def test_pallas_reduce_parity():
+    """The Pallas reduce runs in float32 (Mosaic has no float64), so it
+    matches the NumPy engine to the float32 tolerance — and the plain
+    float32 jit to a few ulps."""
     model, grid, n = _model("summa")
     eng = model.batch(grid)
     stack = _stack(model, grid, n)
     ref = eng.step_times(stack)
-    got = jb.to_jax(eng, use_pallas=True).step_times(stack)
-    assert _rel(got, ref) <= F64_RTOL
+    got = jb.to_jax(eng, dtype="float32", use_pallas=True).step_times(stack)
+    plain = jb.to_jax(eng, dtype="float32").step_times(stack)
+    assert _rel(got, ref) <= F32_RTOL
+    assert _rel(got, plain) <= 1e-6
+
+
+def test_pallas_float64_refused():
+    model, grid, n = _model("summa")
+    with pytest.raises(ValueError, match="float32"):
+        jb.to_jax(model.batch(grid), use_pallas=True)
 
 
 def test_f32_is_looser_than_f64():

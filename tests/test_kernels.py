@@ -104,11 +104,23 @@ def test_flash_attention_property(nq, window, seed):
 
 # ------------------------------------------------------------------ stencil
 @pytest.mark.parametrize("m,n,bm", [(128, 128, 64), (256, 128, 128),
-                                    (192, 256, 64)])
+                                    (192, 256, 64), (96, 128, None)])
 def test_stencil_shapes(m, n, bm):
     f = jax.random.normal(jax.random.key(0), (m, n), jnp.float32)
     out = stencil_pallas(f, bm=bm, interpret=True)
     _assert_close(out, ref.stencil(f), jnp.float32)
+
+
+@pytest.mark.parametrize("m,n", [(1024, 8192), (2048, 16384), (96, 128),
+                                 (12, 4096)])
+def test_stencil_rows_per_block_fits_vmem(m, n):
+    """The default row block divides the field, keeps the (8, 128) rule,
+    and its four double-buffered blocks fit the VMEM budget."""
+    from repro.kernels.stencil import BLOCK_VMEM_BYTES, rows_per_block
+
+    bm = rows_per_block(m, n)
+    assert m % bm == 0 and (bm % 8 == 0 or bm == m)
+    assert 4 * 2 * bm * n * 4 <= BLOCK_VMEM_BYTES
 
 
 def test_stencil_matches_science_app_reference():
@@ -176,7 +188,8 @@ def test_wkv6_matches_model_layer():
 # ----------------------------------------------------------- segment rowmax
 @pytest.mark.parametrize("rows,cols,seg", [(5, 512, 1), (8, 512, 8),
                                            (17, 96, 4), (3, 1024, 64),
-                                           (1, 64, 64)])
+                                           (1, 64, 64), (4, 96, 3),
+                                           (9, 600, 5), (2, 384, 6)])
 def test_segment_rowmax_shapes(rows, cols, seg):
     from repro.kernels.segment_reduce import segment_rowmax_pallas
 

@@ -116,7 +116,7 @@ def test_dryrun_single_cell_compiles():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "smollm-135m", "--shape", "train_4k", "--mesh", "single"],

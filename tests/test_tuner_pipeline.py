@@ -17,7 +17,7 @@ import pytest
 from repro import apps
 from repro.sim.batch import batch_simulator, price_stacks
 from repro.sim.cost import time_tuned_app
-from repro.sim.jax_backend import have_jax, to_jax
+from repro.sim.jax_backend import to_jax
 from repro.search.pipeline import PriceJob, price_job, stream_priced
 from repro.search.tuner import tune_app
 
@@ -25,8 +25,6 @@ TIMED_APPS = [a for a in apps.iter_apps()
               if a.search_space is not None
               and getattr(a, "collective", None) is not None]
 APP_IDS = [a.name for a in TIMED_APPS]
-
-pytestmark = pytest.mark.skipif(not have_jax(), reason="jax not installed")
 
 
 def _leaderboard_key(report):
