@@ -1,4 +1,4 @@
-"""Benchmark driver — one harness per paper table/figure + roofline.
+"""Benchmark driver — one harness per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run           # everything
   PYTHONPATH=src python -m benchmarks.run --only loc_table
@@ -7,9 +7,7 @@
 Prints a ``name,us_per_call,derived`` CSV at the end (microbench section)
 plus the per-table reports above it. The ``mapper_tuning`` and
 ``sim_eval`` lanes write ``BENCH_tuning.json`` / ``BENCH_sim.json``
-(uploaded as CI artifacts next to ``BENCH_mapping.json``); the
-``roofline`` and ``perf_iterations`` sections read previously recorded
-dry-run artifacts and skip cleanly when absent.
+(uploaded as CI artifacts next to ``BENCH_mapping.json``).
 
 Every run additionally aggregates the executed sections' results — each
 harness's ``run()`` returns its machine-readable artifact — into one
@@ -33,9 +31,7 @@ from benchmarks import (
     loc_table,
     mapper_tuning,
     mapping_eval,
-    perf_iterations,
     resilience_bench,
-    roofline_report,
     serve_bench,
     sim_eval,
 )
@@ -58,10 +54,6 @@ SECTIONS = {
     "resilience_bench": ("Fault recovery: warm remap vs cold retune + "
                          "degraded-pricing parity (+ BENCH_resilience.json)",
                          resilience_bench.run),
-    "roofline": ("Roofline table (from dry-run artifacts)",
-                 roofline_report.run),
-    "perf_iterations": ("§Perf hillclimb summary (from recorded artifacts)",
-                        perf_iterations.run),
 }
 
 PERF_JSON = "BENCH_perf.json"
@@ -92,8 +84,6 @@ def _trajectory(sections: dict) -> dict:
     per-section results)."""
     headline: dict = {}
     for key, entry in sections.items():
-        if "skipped" in entry:
-            continue
         res = entry.get("result")
         row: dict = {"elapsed_s": round(entry.get("elapsed_s", 0.0), 3)}
         if key == "sim_eval" and isinstance(res, dict):
@@ -217,12 +207,7 @@ def main(argv=None) -> int:
         title, fn = SECTIONS[key]
         print(f"\n{'=' * 72}\n{title}\n{'=' * 72}")
         t0 = time.perf_counter()
-        try:
-            result = fn()
-        except FileNotFoundError as e:
-            print(f"(skipped: {e} — run repro.launch.dryrun first)")
-            results[key] = {"skipped": str(e)}
-            continue
+        result = fn()
         results[key] = {
             "elapsed_s": time.perf_counter() - t0,
             "result": result,

@@ -47,7 +47,10 @@ def cannon_body(q: int, use_kernel: bool = False):
 
         def step(_, carry):
             c, a, b = carry
-            c = c + local_matmul(a, b, use_kernel)
+            # Scope the accumulate too: XLA fuses it with the dot, and the
+            # fusion may carry either op's name.
+            with jax.named_scope("local_matmul"):
+                c = c + local_matmul(a, b, use_kernel)
             a = shift(a, "y", -1, q)
             b = shift(b, "x", -1, q)
             return (c, a, b)

@@ -19,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.mapper import Mapper
 from repro.core.translate import mesh_from_mapper
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,7 +47,8 @@ def build_grid(
 def shift(x: jax.Array, axis_name: str, offset: int, axis_size: int) -> jax.Array:
     """Cyclic shift of blocks along a mesh axis (Cannon's systolic move)."""
     perm = [(i, (i + offset) % axis_size) for i in range(axis_size)]
-    return jax.lax.ppermute(x, axis_name, perm)
+    with jax.named_scope("shift"):
+        return jax.lax.ppermute(x, axis_name, perm)
 
 
 def skew(x: jax.Array, by_axis: str, along_axis: str, sizes: tuple[int, int],
@@ -66,7 +68,8 @@ def skew(x: jax.Array, by_axis: str, along_axis: str, sizes: tuple[int, int],
         keep = step >= i
         return jnp.where(keep, val, moved)
 
-    return jax.lax.fori_loop(0, n - 1, body, x)
+    with jax.named_scope("skew"):
+        return jax.lax.fori_loop(0, n - 1, body, x)
 
 
 def block_spec(*axes: str | None) -> P:
@@ -92,11 +95,12 @@ def local_matmul(a: jax.Array, b: jax.Array,
     With ``use_kernel=True`` routes through the Pallas MXU kernel
     (repro.kernels.ops.matmul); default jnp.dot for portability.
     """
-    if use_kernel:
-        from repro.kernels import ops as kops
+    with jax.named_scope("local_matmul"):
+        if use_kernel:
+            from repro.kernels import ops as kops
 
-        return kops.matmul(a, b)
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+            return kops.matmul(a, b)
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
 def sharded_matmul_wrapper(
@@ -106,9 +110,15 @@ def sharded_matmul_wrapper(
     out_spec: P,
     check_vma: bool = False,
 ):
-    """Wrap an algorithm body in shard_map + jit over the grid's mesh."""
-    fn = jax.shard_map(
-        body, mesh=grid.mesh, in_specs=in_specs, out_specs=out_spec,
-        check_vma=check_vma,
-    )
-    return jax.jit(fn)
+    """Wrap an algorithm body in shard_map + jit over the grid's mesh.
+
+    Each wrapper is one program build, counted in ``matmul.builds`` and run
+    under the span ``repro.matmul.build``. The caller calls the result at
+    once; in a trace, JAX's own ``PjitFunction(<body>)`` event is that call.
+    """
+    tracing.count("matmul.builds")
+    with tracing.span("matmul.build"):
+        return jax.jit(jax.shard_map(
+            body, mesh=grid.mesh, in_specs=in_specs, out_specs=out_spec,
+            check_vma=check_vma,
+        ))

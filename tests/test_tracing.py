@@ -1,0 +1,150 @@
+"""The program's own spans, counters and named scopes
+(``repro.runtime.tracing``, the matmul entry, the Cannon body).
+
+The multi-device checks run in one child process on 8 CPU devices, as in
+``test_distributed.py``; each test reads its part of the child's report.
+"""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+ALGORITHMS = ["cannon", "summa", "pumma", "johnson", "solomonik", "cosma"]
+
+CHILD = r"""
+import collections, json, re
+from unittest import mock
+import jax, numpy as np
+from jax._src import monitoring
+from jax.sharding import PartitionSpec as P
+from repro.core import Machine, GPU
+from repro.core.commvolume import MatmulProblem
+from repro.matmul import cannon, summa, pumma, johnson, solomonik, cosma
+from repro.matmul.common import make_inputs
+from repro.runtime import tracing
+
+
+def plain_wrapper(grid, body, in_specs, out_spec, check_vma=False):
+    # The parent's entry: one jax.jit of the same shard_map, no spans.
+    return jax.jit(jax.shard_map(body, mesh=grid.mesh, in_specs=in_specs,
+                                 out_specs=out_spec, check_vma=check_vma))
+
+
+jax.config.update("jax_enable_compilation_cache", False)  # every compile counts
+a, b = make_inputs(16, 24, 32, seed=1)
+m4 = Machine(GPU, shape=(2, 2))
+devs4 = jax.devices()[:4]
+grids = {
+    "cannon": cannon.grid_for(m4, devs4),
+    "summa": summa.grid_for(m4, devs4),
+    "pumma": pumma.grid_for(m4, devs4),
+    "johnson": johnson.grid_for(Machine(GPU, shape=(8, 1))),
+    "solomonik": solomonik.grid_for(Machine(GPU, shape=(2, 4)), c=2),
+    "cosma": cosma.grid_for(Machine(GPU, shape=(8, 1)), MatmulProblem(16, 32, 24)),
+}
+# JAX's own events: each lowering to StableHLO and each backend compile.
+WORK = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/core/compile/backend_compile_duration")
+events = collections.Counter()
+monitoring.register_event_duration_secs_listener(
+    lambda name, _secs, **_: events.update([name] if name in WORK else []))
+
+report = {}
+for name, grid in grids.items():
+    mod = globals()[name]
+    mod.matmul(a, b, grid)  # compiles the helpers a first call needs
+    before = tracing.counters()
+    events.clear()
+    spanned = [np.asarray(mod.matmul(a, b, grid)) for _ in range(2)]
+    spanned_events = dict(events)
+    builds = (tracing.counters() - before)["matmul.builds"]
+    with mock.patch.object(mod, "sharded_matmul_wrapper", plain_wrapper):
+        events.clear()
+        plain = [np.asarray(mod.matmul(a, b, grid)) for _ in range(2)]
+        plain_events = dict(events)
+    report[name] = {"builds": builds, "events": [spanned_events, plain_events],
+                    "equal": all(np.array_equal(s, p) for s, p in zip(spanned, plain))}
+
+q = 2
+fn = plain_wrapper(grids["cannon"], cannon.cannon_body(q), (P("x", "y"),) * 2,
+                   P("x", "y"))
+text = fn.lower(a, b).compile().as_text()
+report["op_names"] = sorted(set(re.findall(r'op_name="([^"]+)"', text)))
+print("REPORT " + json.dumps(report))
+"""
+
+
+@pytest.fixture(scope="module")
+def report():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + env.get("PYTHONPATH", "")
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    proc = subprocess.run([sys.executable, "-c", CHILD], capture_output=True,
+                          text=True, timeout=420, env=env)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    line = next(ln for ln in proc.stdout.splitlines() if ln.startswith("REPORT "))
+    return json.loads(line[len("REPORT "):])
+
+
+def test_span_is_a_trace_annotation_that_runs_without_a_profiler():
+    import jax
+
+    from repro.runtime import tracing
+
+    s = tracing.span("matmul.call")
+    assert isinstance(s, jax.profiler.TraceAnnotation)
+    with s:
+        pass
+
+
+def test_counters_count_and_return_a_copy():
+    from repro.runtime import tracing
+
+    before = tracing.counters()
+    tracing.count("test.things")
+    tracing.count("test.things", 2)
+    got = tracing.counters()
+    assert (got - before)["test.things"] == 3
+    got["test.things"] += 100
+    assert (tracing.counters() - before)["test.things"] == 3
+
+
+@pytest.mark.parametrize("algorithm", ALGORITHMS)
+def test_entry_matches_a_plain_jit_of_the_same_body(report, algorithm):
+    assert report[algorithm]["equal"]
+
+
+@pytest.mark.parametrize("algorithm", ALGORITHMS)
+def test_each_call_counts_one_build(report, algorithm):
+    assert report[algorithm]["builds"] == 2
+
+
+@pytest.mark.parametrize("algorithm", ALGORITHMS)
+def test_entry_does_a_plain_jits_work(report, algorithm):
+    """One lowering and one backend compile per call, as a plain jit of the
+    same body does: the spans add no work of JAX's."""
+    spanned, plain = report[algorithm]["events"]
+    assert spanned == plain == {
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": 2,
+        "/jax/core/compile/backend_compile_duration": 2}
+
+
+@pytest.mark.parametrize("scope,op", [
+    ("skew", "jit(_where)/select_n"),   # the skew's predicated copy
+    ("skew", "shift/ppermute"),         # the skew's single-step shifts
+    ("local_matmul", "dot_general"),
+    ("local_matmul", "add"),            # the accumulate beside the dot
+])
+def test_named_scopes_reach_op_names_through_the_loops(report, scope, op):
+    assert any(f"/{scope}/" in n and n.endswith(op) for n in report["op_names"]), \
+        report["op_names"]
+
+
+def test_every_ppermute_is_under_shift(report):
+    permutes = [n for n in report["op_names"] if n.endswith("ppermute")]
+    assert permutes and all("/shift/" in n for n in permutes), permutes
