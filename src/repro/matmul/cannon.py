@@ -2,7 +2,9 @@
 
 Mapper: the paper's ``hierarchical_block2D`` (Fig. 12) — node-block over the
 outer factors, cyclic over the intra-node factors. Swapping in the "runtime
-heuristics" mapper (Fig. 13 strawman) changes only the Mesh device order.
+heuristics" mapper (Fig. 13 strawman) changes only the Mesh device order,
+and so the grid: each grid builds its program on its first call and reuses
+it after (``common.sharded_matmul_wrapper``).
 """
 from __future__ import annotations
 
@@ -66,7 +68,7 @@ def matmul(a: jax.Array, b: jax.Array, grid: MatmulGrid,
     q = grid.shape[0]
     fn = sharded_matmul_wrapper(
         grid,
-        cannon_body(q, use_kernel),
+        cannon_body, (q, use_kernel),
         in_specs=(P("x", "y"), P("x", "y")),
         out_spec=P("x", "y"),
     )

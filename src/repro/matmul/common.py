@@ -5,11 +5,15 @@ produced by a Mapple mapper* (see repro.core.translate). The algorithms
 differ in (a) the processor grid the mapper produces and (b) the collective
 schedule of the body — exactly the paper's framing: the mapper is the
 performance-critical, swappable part.
+
+A grid keeps the programs built on it: each is built on the first call
+with its (body factory, the factory's static arguments, specs) and reused
+by every later call on that grid. The grid's mesh carries the device order,
+so two mappers of one grid shape never share a program.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, Sequence
 
 import jax
@@ -24,10 +28,13 @@ from repro.runtime import tracing
 
 @dataclasses.dataclass(frozen=True)
 class MatmulGrid:
-    """A processor grid + the mesh realizing a Mapple mapper on it."""
+    """A processor grid + the mesh realizing a Mapple mapper on it, and the
+    programs built on it so far (``sharded_matmul_wrapper``)."""
 
     mesh: Mesh
     axis_names: tuple[str, ...]
+    programs: dict = dataclasses.field(default_factory=dict, compare=False,
+                                       repr=False)
 
     @property
     def shape(self) -> tuple[int, ...]:
@@ -105,20 +112,28 @@ def local_matmul(a: jax.Array, b: jax.Array,
 
 def sharded_matmul_wrapper(
     grid: MatmulGrid,
-    body: Callable[..., jax.Array],
+    body_factory: Callable[..., Callable[..., jax.Array]],
+    body_args: tuple,
     in_specs: tuple[P, ...],
     out_spec: P,
-    check_vma: bool = False,
 ):
-    """Wrap an algorithm body in shard_map + jit over the grid's mesh.
+    """The jitted ``shard_map`` of ``body_factory(*body_args)`` over the
+    grid's mesh, for the caller to call at once.
 
-    Each wrapper is one program build, counted in ``matmul.builds`` and run
-    under the span ``repro.matmul.build``. The caller calls the result at
-    once; in a trace, JAX's own ``PjitFunction(<body>)`` event is that call.
+    Every call counts ``matmul.calls``. The first call on ``grid`` with a
+    given (``body_factory``, ``body_args``, ``in_specs``, ``out_spec``)
+    builds the program, counting ``matmul.builds`` under the span
+    ``repro.matmul.build``; later calls return the same program, whose call
+    takes jit's fast path: no trace, no lowering, no compile.
     """
-    tracing.count("matmul.builds")
-    with tracing.span("matmul.build"):
-        return jax.jit(jax.shard_map(
-            body, mesh=grid.mesh, in_specs=in_specs, out_specs=out_spec,
-            check_vma=check_vma,
-        ))
+    tracing.count("matmul.calls")
+    key = (body_factory, body_args, in_specs, out_spec)
+    fn = grid.programs.get(key)
+    if fn is None:
+        tracing.count("matmul.builds")
+        with tracing.span("matmul.build"):
+            fn = grid.programs[key] = jax.jit(jax.shard_map(
+                body_factory(*body_args), mesh=grid.mesh, in_specs=in_specs,
+                out_specs=out_spec, check_vma=False,
+            ))
+    return fn

@@ -54,7 +54,7 @@ def matmul(a: jax.Array, b: jax.Array, grid: MatmulGrid,
            use_kernel: bool = False) -> jax.Array:
     fn = sharded_matmul_wrapper(
         grid,
-        johnson_body(use_kernel),
+        johnson_body, (use_kernel,),
         in_specs=(P("x", "z"), P("z", "y")),
         out_spec=P("x", "y"),
     )

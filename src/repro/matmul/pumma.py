@@ -50,7 +50,7 @@ def matmul(a: jax.Array, b: jax.Array, grid: MatmulGrid,
     q = grid.shape[0]
     fn = sharded_matmul_wrapper(
         grid,
-        summa_body(q, use_kernel),
+        summa_body, (q, use_kernel),
         in_specs=(P("x", "y"), P("x", "y")),
         out_spec=P("x", "y"),
     )

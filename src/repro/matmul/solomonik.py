@@ -101,7 +101,7 @@ def matmul(a: jax.Array, b: jax.Array, grid: MatmulGrid,
         raise ValueError(f"2.5D requires c | q, got q={q}, c={c}")
     fn = sharded_matmul_wrapper(
         grid,
-        solomonik_body(q, c, use_kernel),
+        solomonik_body, (q, c, use_kernel),
         # A, B block-distributed over (x, y), replicated over z.
         in_specs=(P("x", "y"), P("x", "y")),
         out_spec=P("x", "y"),
