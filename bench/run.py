@@ -13,18 +13,52 @@ A run: make the inputs from the seed and build the program's mesh, call the
 entry once (compiling, or loading from JAX's persistent compilation cache in
 ``<checkout>/.bench_jax_cache``), then call it in a closed loop for ``--seconds``.
 With ``--trace 1`` the loop runs under the profiler and the per-layer
-metrics are read from its trace; otherwise the end-to-end metrics are
-reported. After the loop the outputs of two calls (the last, and one drawn
-from the seed among the first few) are compared with the configuration's
-plain reference.
+metrics are read from its trace and from the program's counters; otherwise
+the end-to-end metrics are reported. After the loop the outputs of two
+calls (the last, and one drawn from the seed among the first few) are
+compared with the configuration's plain reference.
 
-Standard output ends with the count of backend compilations inside the
-window, then one JSON line: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device``, (traced) ``breakdown``, and last ``check``, each
-number compared beside its limit. Standard error ends with the same check.
-Without an accelerator, with fewer chips than the cell asks for, or on a
-device missing from ``bench/peaks.json``, the run exits non-zero and prints
-no result.
+Standard output ends with the window's counts (backend compilations, calls,
+set-up phases and, traced, the program's counters), then one JSON line:
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, (traced)
+``breakdown``, and last ``check``, each number compared beside its limit.
+Standard error ends with the same check. Without an accelerator, with fewer
+chips than the cell asks for, or on a device missing from
+``bench/peaks.json``, the run exits non-zero and prints no result.
+
+Adding a configuration and its cell
+-----------------------------------
+A configuration brings, as new files:
+
+- its config file (``configs`` entry ``file``), holding ``driver``, the
+  ``check`` limits of ``correct``, and ``cpu_sizes``: the keys the CPU
+  tests override to run it in seconds (``tests/bench/bench_helpers.py``).
+  A run here never reads ``cpu_sizes``.
+- its driver, ``bench/drivers/<driver>.py``: a class ``Driver(config,
+  seed, devices)`` with
+  - ``steps_per_call``: steps one call makes;
+  - ``call()``: one call of the program's entry, returning what the check
+    compares. It is held while later calls run, so it must not be a buffer
+    that the next call donates: a train driver returns its loss and logits,
+    not its state;
+  - ``work()``: counts per step from shapes; ``flops`` and ``hbm_bytes`` at
+    least (``mfu`` reads them);
+  - ``check(kept)``: compare the kept outputs with the reference, freeing
+    the program's state first; a list of ``{"name", "value", "limit"}``;
+  - ``control()``: the same numbers, read off the control (the reference
+    one precision below the configuration's) in the program's place.
+- its plain reference, beside the driver under ``bench/references/``,
+  importing nothing of the program;
+- its planted faults, ``tests/bench/faults/<driver>.py``: ``patches(kind)``
+  for ``control`` and each fault, and ``FAULTS``, each fault's chip counts;
+- readers of its own per-layer metrics, ``bench/metrics/<metric>.py``: a
+  function ``read(ctx)`` of a ``Context`` that returns a number, or None
+  where the window holds nothing to read (never 0 for a missing scope,
+  span or counter).
+
+A cell is a ``workloads`` entry over a traffic mix; it adds its name to the
+``workloads`` lists of ``dispatch_ms``, ``mfu`` and ``device_idle.step``,
+which every cell reports.
 """
 from __future__ import annotations
 
@@ -33,12 +67,14 @@ import time
 T0 = time.perf_counter()  # set-up is timed from the start of the process
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
+import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -150,7 +186,7 @@ class CompileCounter:
 
 @dataclasses.dataclass
 class Context:
-    """What a per-layer metric reader reads."""
+    """What a per-layer metric reader reads: the traced window, reduced."""
 
     summary: object            # trace.Summary of the traced window
     steps: int                 # steps completed in it
@@ -159,22 +195,43 @@ class Context:
     work: dict                 # the driver's counts per step
     peaks: dict                # this device's row of bench/peaks.json
     dispatch_s: float          # host time in the entry's calls
+    counters: collections.Counter  # the program's counts in the window
+    spans: list                # the program's host spans (trace.Span)
+    tf_op: dict                # HLO text -> the op's tf_op
+
+    def span_ns(self, name: str) -> float:
+        """Host time in the program's spans ``repro.<name>`` inside the
+        window (0 where it has none)."""
+        from bench import trace as tracemod
+
+        return tracemod.span_ns(self.spans, name, self.summary.window)
+
+    def scoped_op_ns(self, pick) -> float:
+        """Device leaf-op time, mean over chips, of the ops whose named
+        scopes (``trace.scopes`` of their ``tf_op``) ``pick`` accepts (0
+        where none does)."""
+        from bench import trace as tracemod
+
+        return tracemod.scoped_op_ns(self.summary, self.tf_op, pick)
 
 
 # --------------------------------------------------------------------- run
 def run(bench: Bench, cell_name: str, seed: int, seconds: float, traced: bool,
-        devices=None) -> tuple[dict, dict]:
+        devices=None, keep: Path | None = None) -> tuple[dict, dict]:
     """One run of a cell: the result line as a dict, and the window's counts
     of calls and backend compilations with the set-up's phases and, traced,
-    the device's idle time by the harness span the host was in, in seconds.
+    the program's counters (``counter.<name>``) and the device's idle time
+    by the harness span the host was in, in seconds.
 
     ``devices`` skips the look for accelerators (and the device's peaks,
     which are then those of the first row of the table): tests drive the
-    rest of a run on the CPU this way.
+    rest of a run on the CPU this way. ``keep``, traced, is where the raw
+    trace (``.xplane.pb``) is copied before its directory goes.
     """
     import jax
 
     from bench import trace as tracemod
+    from repro.runtime import tracing
     from repro.runtime.compile_cache import enable_compile_cache
 
     notes = {"import_s": time.perf_counter() - T0}
@@ -207,6 +264,7 @@ def run(bench: Bench, cell_name: str, seed: int, seconds: float, traced: bool,
     counter = CompileCounter()
     tmp = tempfile.TemporaryDirectory() if traced else None
     if traced:
+        before = tracing.counters()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(tmp.name, profiler_options=opts)
@@ -235,6 +293,7 @@ def run(bench: Bench, cell_name: str, seed: int, seconds: float, traced: bool,
     counter.active = False
     if traced:
         jax.profiler.stop_trace()
+        counted = tracing.counters() - before
     if calls - 1 != checked_at:
         kept.append(out)
     del out
@@ -246,12 +305,18 @@ def run(bench: Bench, cell_name: str, seed: int, seconds: float, traced: bool,
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": memory_peak}
     if traced:
-        summary = tracemod.summarize(
-            tracemod.load(tracemod.find_xplane(tmp.name)), [d.id for d in used])
+        xplane = tracemod.find_xplane(tmp.name)
+        if keep is not None:
+            keep.parent.mkdir(parents=True, exist_ok=True)
+            shutil.copy(xplane, keep)
+        trace = tracemod.load(xplane)
+        tf_op = tracemod.read_tf_ops(xplane)
         tmp.cleanup()
+        summary = tracemod.summarize(trace, [d.id for d in used])
         ctx = Context(summary=summary, steps=steps,
                       window_s=summary.window_ns * 1e-9, chips=len(used),
-                      work=drv.work(), peaks=peaks, dispatch_s=dispatch_s)
+                      work=drv.work(), peaks=peaks, dispatch_s=dispatch_s,
+                      counters=counted, spans=trace.program, tf_op=tf_op)
         metrics = {}
         for m in bench.metrics("per_layer", cell_name):
             value = bench.module("metrics", m["name"]).read(ctx)
@@ -263,6 +328,7 @@ def run(bench: Bench, cell_name: str, seed: int, seconds: float, traced: bool,
                      "idle_gaps": summary.longest_gaps(10)}
         notes.update({f"idle_s_in_{name}": ns * 1e-9
                        for name, ns in summary.gap_ns_by_span().items()})
+        notes.update({f"counter.{name}": n for name, n in sorted(counted.items())})
     else:
         e2e = {"step_ms": window_s / steps * 1e3, "setup_s": setup_s}
         metrics = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}

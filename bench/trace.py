@@ -4,7 +4,12 @@ A traced run records, on each chip's plane ``/device:TPU:<n>``, a line
 ``XLA Ops`` whose events are the HLO instructions that ran, named by their
 HLO text (``%fusion.3 = f32[...] fusion(...)``). Control flow nests: a
 ``while`` event spans the events of its body. The host's plane holds the
-harness spans (``bench.dispatch``, ``bench.block``) on the same clock.
+harness spans (``bench.dispatch``, ``bench.block``) and the program's own
+(``repro.<name>``, ``repro.runtime.tracing``) on the same clock. Each op's
+metadata carries a ``tf_op`` stat, the path of transforms and named scopes
+it was traced under (``jit(body)/shard_map/skew/while/.../ppermute:``);
+``jax.profiler.ProfileData`` does not give it, so ``read_tf_ops`` reads it
+from the raw protobuf.
 
 From those this module computes, for each chip and inside a window:
 
@@ -14,7 +19,9 @@ From those this module computes, for each chip and inside a window:
 - collective time, and the part of it during which no other op runs on that
   chip (exposed collective time);
 - idle gaps, each attributed to the harness span the host was in at the
-  gap's midpoint.
+  gap's midpoint;
+- host time in the program's spans, and leaf-op time under its named
+  scopes.
 
 Times are in nanoseconds, as ``jax.profiler.ProfileData`` gives them.
 """
@@ -30,6 +37,7 @@ from pathlib import Path
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 #: HLO opcodes (and their async halves) that move data between chips.
 COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
                "reduce-scatter", "all-to-all", "collective-broadcast",
@@ -66,6 +74,9 @@ class Span:
 class Trace:
     ops: dict[int, list[Op]]          # chip -> every op event, nested ones too
     spans: list[Span]
+    #: the program's spans, without the "repro." prefix, ordered by start
+    #: and, at one start, longest first
+    program: list[Span] = dataclasses.field(default_factory=list)
 
 
 def load(path: str | Path) -> Trace:
@@ -74,6 +85,7 @@ def load(path: str | Path) -> Trace:
     data = ProfileData.from_file(str(path))
     ops: dict[int, list[Op]] = {}
     spans: list[Span] = []
+    program: list[Span] = []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
@@ -82,13 +94,15 @@ def load(path: str | Path) -> Trace:
                     Op(e.name, e.start_ns, e.end_ns)
                     for e in line.events)
             elif plane.name.startswith("/host:"):
-                spans.extend(
-                    Span(e.name[len(SPAN_PREFIX):], e.start_ns, e.end_ns)
-                    for e in line.events if e.name.startswith(SPAN_PREFIX))
+                for e in line.events:
+                    for prefix, out in ((SPAN_PREFIX, spans), (PROGRAM_PREFIX, program)):
+                        if e.name.startswith(prefix):
+                            out.append(Span(e.name[len(prefix):], e.start_ns, e.end_ns))
     for chip_ops in ops.values():
         chip_ops.sort(key=lambda o: (o.start, -o.end))
     spans.sort(key=lambda s: s.start)
-    return Trace(ops=ops, spans=spans)
+    program.sort(key=lambda s: (s.start, -s.end))
+    return Trace(ops=ops, spans=spans, program=program)
 
 
 def find_xplane(directory: str | Path) -> Path:
@@ -232,3 +246,102 @@ def summarize(trace: Trace, chips: list[int],
             exposed_collective_ns=length(subtract(coll, other)),
             gaps=subtract([(lo, hi)], busy))
     return Summary(window=window, chips=out, spans=trace.spans)
+
+
+# ---------------------------------------------------- the program's own marks
+def span_ns(spans: list[Span], name: str, window: tuple[float, float]) -> float:
+    """Host time in the spans called ``name``, clipped to ``window``."""
+    lo, hi = window
+    return sum(max(0.0, min(s.end, hi) - max(s.start, lo))
+               for s in spans if s.name == name)
+
+
+def scopes(tf_op: str) -> set[str]:
+    """The components of a ``tf_op`` path: the named scopes the op ran
+    under, among the names of the transforms and control flow."""
+    return set(tf_op.rsplit(":", 1)[0].split("/")) if tf_op else set()
+
+
+def scoped_op_ns(summary: Summary, tf_op: dict[str, str], pick) -> float:
+    """Leaf op time of the ops whose ``scopes`` ``pick`` accepts, averaged
+    over chips. An op ``tf_op`` does not name is under no scope."""
+    return summary.op_ns(lambda text: pick(scopes(tf_op.get(text, ""))))
+
+
+# The raw XSpace protobuf (tsl/profiler/protobuf/xplane.proto), read for the
+# fields needed here: XSpace.planes = 1; XPlane.name = 2, lines = 3
+# (skipped), event_metadata = 4, stat_metadata = 5 (maps: key = 1, value =
+# 2); XEventMetadata.name = 2, stats = 5; XStatMetadata.id = 1, name = 2;
+# XStat.metadata_id = 1, str_value = 5, ref_value = 7 (the id of the stat
+# metadata whose name is the string).
+def _varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of each field of a serialized message: an int
+    for a varint, a memoryview for a length-delimited or fixed-width one."""
+    i = 0
+    while i < len(buf):
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire in (1, 2, 5):
+            if wire == 2:
+                n, i = _varint(buf, i)
+            else:
+                n = 8 if wire == 1 else 4
+            value, i = buf[i:i + n], i + n
+        else:
+            raise ValueError(f"unsupported protobuf wire type {wire}")
+        yield key >> 3, value
+
+
+def _map_value(entry):
+    return next((v for f, v in _fields(entry) if f == 2), b"")
+
+
+def read_tf_ops(path: str | Path) -> dict[str, str]:
+    """HLO text -> ``tf_op`` of every event the device planes name: "" where
+    the metadata carries none, as for copies and conversions XLA inserts. An
+    HLO text that carries two different ``tf_op``s is an error."""
+    out: dict[str, str] = {}
+    for f, plane in _fields(memoryview(Path(path).read_bytes())):
+        if f != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for g, v in _fields(plane):
+            if g == 2:
+                name = bytes(v).decode()
+            elif g == 4:
+                events.append(_map_value(v))
+            elif g == 5:
+                stat = dict(_fields(_map_value(v)))
+                stat_names[stat.get(1, 0)] = bytes(stat.get(2, b"")).decode()
+        if not DEVICE_PLANE.match(name):
+            continue
+        tf_op_ids = {i for i, n in stat_names.items() if n == "tf_op"}
+        for event in events:
+            text, tf_op = "", ""
+            for g, v in _fields(event):
+                if g == 2:
+                    text = bytes(v).decode()
+                elif g == 5:
+                    stat = dict(_fields(v))
+                    if stat.get(1, 0) in tf_op_ids:
+                        tf_op = (bytes(stat[5]).decode() if 5 in stat
+                                 else stat_names[stat.get(7, 0)])
+            seen = out.setdefault(text, tf_op)
+            if seen and tf_op and seen != tf_op:
+                raise ValueError(f"{text!r} carries two tf_ops: "
+                                 f"{seen!r} and {tf_op!r}")
+            out[text] = seen or tf_op
+    return out

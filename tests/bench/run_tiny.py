@@ -1,57 +1,46 @@
 """Drive runs of tiny cells on CPU devices, with the timed path sound or
 broken underneath, and print one JSON line per scenario.
 
-    python tests/bench/run_tiny.py <spec> <scenario>:<cell> ...
+    python tests/bench/run_tiny.py <spec> [--overlay DIR] <scenario>:<cell> ...
 
 Scenarios: ``sound`` (a run; also prints the driver's counts per step),
-``readings`` (the control's readings against the limits, as
-``bench/calibrate.py`` reads them), ``control`` (a run with the control in
-the program's place), and the faults ``unchanged`` (a step returns its state
-unchanged), ``no_exchange`` (the exchange between chips left out) and
-``altered`` (one answer altered where it is produced). A run skips the
-harness's look for a chip and otherwise runs as on the chip.
+``traced`` (a run under the profiler; prints its per-layer metrics and the
+program's counters), ``readings`` (the control's readings against the
+limits, as ``bench/calibrate.py`` reads them), ``control`` (a run with the
+control in the program's place), and each fault that the driver's
+``tests/bench/faults/<driver>.py`` plants. A run skips the harness's look
+for a chip and otherwise runs as on the chip. ``--overlay`` names a
+directory laid out like the repository whose ``bench/``,
+``tests/bench/faults/`` and ``src/`` are searched first
+(``bench_helpers``).
 """
+import argparse
 import json
 import sys
 from contextlib import ExitStack
 from pathlib import Path
-from unittest import mock
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
-from bench import run as harness  # noqa: E402
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("spec", type=Path)
+    ap.add_argument("--overlay", type=Path)
+    ap.add_argument("scenarios", nargs="+", metavar="scenario:cell")
+    args = ap.parse_args(argv)
+    if args.overlay is not None:
+        sys.path.insert(0, str(args.overlay / "src"))
 
+    import jax
 
-def _altered(entry):
-    def wrapped(*args, **kwargs):
-        out = entry(*args, **kwargs)
-        return out.at[1, 2].add(1.0 + jnp.abs(out[1]).max())
-    return wrapped
+    from bench import run as harness
+    from bench_helpers import faults
 
-
-def faults(kind: str) -> list:
-    """Patches that break the timed path of the Cannon program underneath."""
-    from bench.references import matmul as reference
-    from repro.matmul import cannon
-
-    zero = lambda a, b, *_: jnp.zeros((a.shape[0], b.shape[1]), jnp.float32)  # noqa: E731
-    return {
-        "control": [mock.patch.object(
-            cannon, "matmul", lambda a, b, _grid: reference.control_product(a, b))],
-        "unchanged": [mock.patch.object(cannon, "local_matmul", zero)],
-        "no_exchange": [mock.patch.object(cannon, "shift", lambda x, *a: x),
-                        mock.patch.object(cannon, "skew", lambda x, *a, **k: x)],
-        "altered": [mock.patch.object(cannon, "matmul", _altered(cannon.matmul))],
-    }[kind]
-
-
-def main(spec: str, scenarios: list[str]) -> None:
-    bench = harness.Bench(Path(spec), [ROOT / "bench"])
-    for item in scenarios:
+    dirs = ([args.overlay / "bench"] if args.overlay else []) + [ROOT / "bench"]
+    bench = harness.Bench(args.spec, dirs)
+    for item in args.scenarios:
         kind, cell = item.split(":")
         entry = bench.cell(cell)
         config = bench.config(entry["config"])
@@ -63,11 +52,14 @@ def main(spec: str, scenarios: list[str]) -> None:
             out["limits"] = config["check"]
         else:
             with ExitStack() as stack:
-                for p in ([] if kind == "sound" else faults(kind)):
-                    stack.enter_context(p)
-                res, counts = harness.run(bench, cell, 3_000_000_019, 0.3, False,
-                                          devices=jax.devices())
+                if kind not in ("sound", "traced"):
+                    for p in faults(config["driver"], args.overlay).patches(kind):
+                        stack.enter_context(p)
+                res, counts = harness.run(bench, cell, 3_000_000_019, 0.3,
+                                          kind == "traced", devices=jax.devices())
             out.update(correct=res["correct"], check=res["check"], **counts)
+            if kind == "traced":
+                out["metrics"] = res["metrics"]
             if kind == "sound":
                 drv = bench.module("drivers", config["driver"]).Driver(
                     config, 11, jax.devices()[:entry["chips"]])
@@ -76,4 +68,4 @@ def main(spec: str, scenarios: list[str]) -> None:
 
 
 if __name__ == "__main__":
-    main(sys.argv[1], sys.argv[2:])
+    main()

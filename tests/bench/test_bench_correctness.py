@@ -1,21 +1,22 @@
 """The comparison that decides ``correct``, at sizes the CPU holds.
 
-Every cell runs at tiny sizes on four CPU devices through the harness (its
-look for a chip skipped): sound, ``correct`` is true; with the control, the
-reference in the precision below the configuration's, in the program's
-place, or with the timed path broken underneath in each way the cell can
-break, it is false. The control's readings, as ``bench/calibrate.py`` takes
-them, lie above a limit of the configuration.
+Every cell of ``BENCHMARK.json`` runs at its configuration's ``cpu_sizes``
+on four CPU devices through the harness (its look for a chip skipped):
+sound, ``correct`` is true; with the control, the reference in the
+precision below the configuration's, in the program's place, or with the
+timed path broken underneath in each way that its driver's
+``tests/bench/faults/<driver>.py`` plants on the cell's chips, it is false.
+The control's readings, as ``bench/calibrate.py`` takes them, lie above a
+limit of the configuration.
 """
 import json
 
 import pytest
 
-from bench_helpers import run_python, write_tiny_bench
+from bench_helpers import cell_faults, read_spec, run_python, write_tiny_bench
 
-CELLS = ["cannon-16384.x1", "cannon-16384.x4"]
-FAULTS = [("unchanged", c) for c in CELLS] + [("altered", c) for c in CELLS] + [
-    ("no_exchange", c) for c in CELLS if c.endswith(".x4")]
+CELLS = [w["name"] for w in read_spec()["workloads"]]
+FAULTS = cell_faults()
 
 
 @pytest.fixture(scope="module")

@@ -108,6 +108,22 @@ def test_new_cell_config_and_metric_need_only_new_files(tmp_path):
     assert after == before
 
 
+def test_tiny_bench_needs_cpu_sizes(tmp_path):
+    """A configuration without ``cpu_sizes`` is refused by name, not run at
+    its chip sizes on the CPU."""
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    config = json.loads((ROOT / "bench/configs/cannon-16384.json").read_text())
+    del config["cpu_sizes"]
+    (tmp_path / "bench/configs").mkdir(parents=True)
+    (tmp_path / "bench/configs/bare.json").write_text(json.dumps(config))
+    spec["configs"].append({"name": "bare", "source": "test",
+                            "file": "bench/configs/bare.json", "reduced": [],
+                            "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    with pytest.raises(KeyError, match="bench/configs/bare.json has no 'cpu_sizes'"):
+        write_tiny_bench(tmp_path / "tiny", overlay=tmp_path)
+
+
 def test_without_a_chip_the_command_fails_and_prints_no_result(tmp_path):
     proc = run_python(["bench/run.py", "--workload", "cannon-16384.x1",
                        "--seed", "3000000001", "--seconds", "1", "--trace", "0"],

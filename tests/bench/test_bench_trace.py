@@ -1,5 +1,7 @@
 """The trace reduction (``bench/trace.py``), on intervals small enough to
 work out by hand and on a small trace recorded on a v5e:2x2 host."""
+import collections
+
 import pytest
 
 from bench_helpers import ROOT
@@ -100,10 +102,12 @@ def test_metric_readers_on_recorded_trace(recorded):
         summary=recorded, steps=steps, window_s=recorded.window_ns * 1e-9,
         chips=4, peaks=peaks, dispatch_s=0.1,
         work={"flops": 2 * n ** 3, "hbm_bytes": 12 * n * n,
-              "matmul_flops_per_chip": q * 2 * (n // q) ** 3})
+              "matmul_flops_per_chip": q * 2 * (n // q) ** 3},
+        counters=collections.Counter(), spans=T.load(RECORDED).program,
+        tf_op=T.read_tf_ops(RECORDED))
     read = {m: b.module("metrics", m).read(ctx) for m in (
         "matmul_roofline", "collective_exposed_ms", "device_idle.step", "mfu",
-        "dispatch_ms")}
+        "dispatch_ms", "skew_ms", "entry_cache_hits")}
     matmul_s = sum(MATMUL.values()) / 4 * 1e-9
     assert read["matmul_roofline"] == pytest.approx(
         100 * steps * q * 2 * 512 ** 3 / peaks["bf16_flops_per_s"] / matmul_s)
@@ -119,3 +123,5 @@ def test_metric_readers_on_recorded_trace(recorded):
         100 * least_s / ((WINDOW[1] - WINDOW[0]) * 1e-9 / steps))
     assert read["dispatch_ms"] == pytest.approx(50.0)
     assert 0 < read["matmul_roofline"] <= 100 and 0 < read["mfu"] <= 100
+    # Recorded before the program had scopes and counters: nothing to read.
+    assert read["skew_ms"] is None and read["entry_cache_hits"] is None

@@ -1,8 +1,10 @@
-"""The program's own instrumentation read from a trace
-(``bench/program_trace.py``): the ``tf_op`` metadata reader, scope and span
-attribution and the cut of each call into phases worked by hand, the
-readings' rule, two v5e:2x2 traces (recorded before the program had spans
-or scopes, and with them), and a traced run on CPU devices."""
+"""The program's own instrumentation read from a trace: the ``tf_op``
+metadata reader and the scope and span attribution that metric readers get
+(``bench/trace.py``, ``bench/run.py``'s ``Context``), and the cut of each
+call that builds into phases with its readings (``bench/program_trace.py``),
+worked by hand, on two v5e:2x2 traces (recorded before the program had
+spans or scopes, and with them), and on a traced run on CPU devices."""
+import collections
 import json
 import textwrap
 
@@ -10,6 +12,7 @@ import pytest
 
 from bench_helpers import ROOT, run_python, write_tiny_bench
 from bench import program_trace as PT
+from bench import run as harness
 from bench import trace as T
 
 RECORDED = ROOT / "tests/bench/data/cannon-1024.x4.xplane.pb"
@@ -69,7 +72,7 @@ def test_tf_ops_read_from_a_raw_xspace(tmp_path):
         _plane("/device:TPU:1", {"%a": SKEW + "shift/ppermute:",
                                  "%c": "jit(body)/dot_general:"}, by_ref=True),
         _plane("/host:CPU", {"%a": "a host event, not read"}))
-    assert PT.read_tf_ops(path) == {"%a": SKEW + "shift/ppermute:", "%b": "",
+    assert T.read_tf_ops(path) == {"%a": SKEW + "shift/ppermute:", "%b": "",
                                     "%c": "jit(body)/dot_general:"}
 
 
@@ -77,14 +80,14 @@ def test_two_tf_ops_for_one_hlo_text_are_an_error(tmp_path):
     path = _xspace(tmp_path, _plane("/device:TPU:0", {"%a": "x/ppermute:"}),
                    _plane("/device:TPU:1", {"%a": "y/ppermute:"}))
     with pytest.raises(ValueError, match="two tf_ops"):
-        PT.read_tf_ops(path)
+        T.read_tf_ops(path)
 
 
 def test_scopes_are_the_components_of_the_path():
-    assert PT.scopes(SKEW + "shift/ppermute:") == {
+    assert T.scopes(SKEW + "shift/ppermute:") == {
         "jit(body)", "shard_map", "skew", "while", "body", "closed_call",
         "shift", "ppermute"}
-    assert PT.scopes("") == set()
+    assert T.scopes("") == set()
 
 
 # ------------------------------------------------------------ worked by hand
@@ -122,11 +125,28 @@ def hand_trace(marks: bool = True) -> PT.ProgramTrace:
     return PT.ProgramTrace(summary=summary, spans=spans, tf_op=tf_op)
 
 
+def context(pt: PT.ProgramTrace, counters: dict | None = None) -> harness.Context:
+    """What the harness hands a metric reader for ``pt``'s window of two
+    steps: the program's spans without the phases cut from JAX's events."""
+    phases = {f"matmul.{p}" for p in PT.PHASES}
+    return harness.Context(
+        summary=pt.summary, steps=2, window_s=pt.summary.window_ns * 1e-9,
+        chips=len(pt.summary.chips), work={}, peaks={}, dispatch_s=0.0,
+        counters=collections.Counter(counters or {}),
+        spans=[s for s in pt.spans if s.name not in phases], tf_op=pt.tf_op)
+
+
+def read(metric: str, ctx: harness.Context):
+    b = harness.Bench(ROOT / "BENCHMARK.json", [ROOT / "bench"])
+    return b.module("metrics", metric).read(ctx)
+
+
 def test_scope_attribution_worked_by_hand():
-    pt = hand_trace()
-    assert pt.scoped_op_ns(lambda s: "skew" in s) == 20 + 25
-    assert pt.scoped_op_ns(lambda s: "local_matmul" in s) == 10
-    assert pt.scoped_op_ns(lambda s: not s & PT.SCOPES) == 5
+    ctx = context(hand_trace())
+    assert ctx.scoped_op_ns(lambda s: "skew" in s) == 20 + 25
+    assert ctx.scoped_op_ns(lambda s: "local_matmul" in s) == 10
+    assert ctx.scoped_op_ns(lambda s: not s & PT.SCOPES) == 5
+    assert read("skew_ms", ctx) == pytest.approx(45 / 2 * 1e-6)
 
 
 def test_calls_cut_into_phases_at_jaxs_events():
@@ -141,7 +161,8 @@ def test_program_spans_worked_by_hand():
     pt = hand_trace()
     assert pt.summary.chips[0].gaps == [(0, 10), (60, 70), (80, 85), (90, 100)]
     assert [pt.span_ns(f"matmul.{s}") for s in PT.PHASES] == [5 + 3, 42, 16, 2]
-    assert pt.span_ns("matmul.build") == 3 + 1
+    assert context(pt).span_ns("matmul.build") == 3 + 1
+    assert context(pt).span_ns("matmul.call") == 0
     # Midpoints 5, 65, 82.5, 95: the innermost span holding each.
     assert pt.gap_ns_by_span() == {"matmul.trace": 10, "matmul.load": 10,
                                    None: 15}
@@ -150,16 +171,17 @@ def test_program_spans_worked_by_hand():
 @pytest.mark.parametrize("builds,expected", [
     (None, dict.fromkeys(["entry_builds_per_step", "entry_trace_ms",
                           "entry_lower_ms", "entry_load_ms", "entry_launch_ms",
-                          "skew_ms", "unscoped_ms"])),
+                          "unscoped_ms"])),
     (0, {"entry_builds_per_step": 0.0, "entry_trace_ms": 0.0,
          "entry_lower_ms": 0.0, "entry_load_ms": 0.0, "entry_launch_ms": 0.0,
-         "skew_ms": 0.0, "unscoped_ms": 0.0}),
+         "unscoped_ms": 5 / 2 * 1e-6}),
     (2, {"entry_builds_per_step": 1.0, "entry_trace_ms": (8 + 4) / 2 * 1e-6,
          "entry_lower_ms": 42 / 2 * 1e-6, "entry_load_ms": 16 / 2 * 1e-6,
-         "entry_launch_ms": 2 / 2 * 1e-6, "skew_ms": 45 / 2 * 1e-6,
-         "unscoped_ms": 5 / 2 * 1e-6}),
+         "entry_launch_ms": 2 / 2 * 1e-6, "unscoped_ms": 5 / 2 * 1e-6}),
 ])
 def test_readings_worked_by_hand(builds, expected):
+    """Scope readings do not depend on builds; the phases are 0.0 where
+    the window built nothing, and nothing is read without counts."""
     assert PT.readings(hand_trace(), 2, builds) == pytest.approx(expected)
 
 
@@ -172,7 +194,7 @@ def test_lost_instrumentation_raises(lost, missing):
         pt.spans = [s for s in pt.spans if s.name != "matmul.build"]
     elif lost == "scopes":
         pt.tf_op = {text: "jit(body)/shard_map/while:" for text in pt.tf_op}
-    with pytest.raises(RuntimeError, match=f"2 builds in the window.*{missing}"):
+    with pytest.raises(RuntimeError, match=missing):
         PT.readings(pt, 2, 2)
 
 
@@ -195,12 +217,18 @@ def test_recorded_trace_gives_every_op_one_tf_op(recorded):
 
 def test_recorded_trace_predates_the_programs_spans(recorded):
     """Recorded before the program had spans or scopes: nothing to read
-    without its counters, 0.0 without builds, and an error with builds."""
+    without its counters; with them, its ops under no scope are an error
+    whatever was built, and so are builds without their span. The metric
+    readers of the scope and the counters read nothing."""
     assert recorded.spans == []
     assert set(PT.readings(recorded, 2, None).values()) == {None}
-    assert set(PT.readings(recorded, 2, 0).values()) == {0.0}
+    with pytest.raises(RuntimeError, match="no op under a scope"):
+        PT.readings(recorded, 2, 0)
     with pytest.raises(RuntimeError, match="no span repro.matmul.build"):
         PT.readings(recorded, 2, 2)
+    ctx = context(recorded)
+    assert read("skew_ms", ctx) is None
+    assert read("entry_cache_hits", ctx) is None
 
 
 @pytest.fixture(scope="module")
@@ -224,13 +252,22 @@ def test_scoped_trace_reads_hand_sums(scoped):
         "entry_lower_ms": 76676300 / 2 * 1e-6,
         "entry_load_ms": 30726557 / 2 * 1e-6,
         "entry_launch_ms": 2896850 / 2 * 1e-6,
-        "skew_ms": 25328.75 / 2 * 1e-6,
         "unscoped_ms": 9337.75 / 2 * 1e-6})
     jit_calls = [(148458612, 206203106), (209047655, 272262488)]
     assert [(s.start, s.end) for s in scoped.spans if s.name == "matmul.trace"][0][0] \
         == jit_calls[0][0]
     assert sum(scoped.span_ns(f"matmul.{p}") for p in PT.PHASES) == sum(
         hi - lo for lo, hi in jit_calls)
+
+
+@pytest.mark.parametrize("calls,builds,hits", [(2, 2, 0.0), (4, 1, 75.0), (2, 0, 100.0)])
+def test_metric_readers_on_scoped_trace(scoped, calls, builds, hits):
+    """``skew_ms`` sums the same leaf ops under ``skew`` as the hand sums
+    above; ``entry_cache_hits`` is the share of calls that built nothing."""
+    ctx = context(scoped, {"matmul.calls": calls, "matmul.builds": builds})
+    assert read("skew_ms", ctx) == pytest.approx(25328.75 / 2 * 1e-6)
+    assert read("entry_cache_hits", ctx) == pytest.approx(hits)
+    assert ctx.span_ns("matmul.build") > 0
 
 
 def test_scoped_trace_names_every_shift(scoped):
@@ -244,21 +281,28 @@ def test_scoped_trace_names_every_shift(scoped):
 # ------------------------------------------------- a traced run on the CPU
 @pytest.fixture(scope="module")
 def cpu_run(tmp_path_factory):
+    """A traced run of the tiny x4 cell through the harness, its trace kept,
+    and the program's counts over the whole process beside the window's."""
     tmp = tmp_path_factory.mktemp("program_trace")
     spec = write_tiny_bench(tmp)
+    kept = tmp / "kept.xplane.pb"
     script = textwrap.dedent(f"""
         import json, sys
         sys.path[:0] = [{str(ROOT)!r}, {str(ROOT / "src")!r}]
         from pathlib import Path
         import jax
-        from bench import program_trace as PT, run as harness
+        from bench import program_trace as PT, run as harness, trace as T
+        from repro.runtime import tracing
         b = harness.Bench(Path({str(spec)!r}), [Path({str(ROOT / "bench")!r})])
-        result, counts, pt, builds = PT.traced_run(
-            b, "cannon-16384.x4", 3_000_000_019, 0.3,
-            Path({str(tmp / "kept.xplane.pb")!r}), devices=jax.devices())
+        before = tracing.counters()
+        result, counts = harness.run(b, "cannon-16384.x4", 3_000_000_019, 0.3, True,
+                                     devices=jax.devices(), keep=Path({str(kept)!r}))
+        process = tracing.counters() - before
+        pt = PT.load({str(kept)!r}, [d.id for d in jax.devices()[:4]])
         print(json.dumps({{
-            "correct": result["correct"], "calls": counts["calls"],
-            "builds": builds, "window": pt.summary.window,
+            "correct": result["correct"], "counts": counts,
+            "metrics": result["metrics"], "process": process,
+            "readings": PT.readings(pt, result["attempted"], 0),
             "harness": [(s.name, s.start, s.end) for s in pt.summary.spans],
             "program": [(s.name, s.start, s.end) for s in pt.spans]}}))
     """)
@@ -267,23 +311,30 @@ def cpu_run(tmp_path_factory):
     return json.loads(proc.stdout.splitlines()[-1])
 
 
-def test_cpu_run_counts_one_build_per_call(cpu_run):
-    assert cpu_run["correct"] and cpu_run["calls"] >= 2
-    assert cpu_run["builds"] == cpu_run["calls"]
+def test_cpu_run_builds_in_warmup_only(cpu_run):
+    """The warm-up call builds the program once; the window's calls, as the
+    harness's own counters read them, build none and are all counted."""
+    counts = cpu_run["counts"]
+    assert cpu_run["correct"] and counts["calls"] >= 2
+    assert cpu_run["process"]["matmul.builds"] == 1
+    assert "counter.matmul.builds" not in counts
+    assert counts["counter.matmul.calls"] == counts["calls"]
+    assert cpu_run["metrics"]["entry_cache_hits"]["value"] == 100.0
 
 
-def test_cpu_run_phases_lie_inside_dispatch_spans(cpu_run):
-    """Each call's build, and then its four phases in order and end to end,
-    inside that call's dispatch span, on the profiler's one clock."""
+def test_cpu_run_dispatch_spans_hold_no_build(cpu_run):
+    """No dispatch span of the window holds a ``repro.matmul.build``, so
+    no call is cut into phases and they read 0.0; a CPU trace holds no op
+    on a chip, so no scope reading is made."""
     dispatch = [s for s in cpu_run["harness"] if s[0] == "dispatch"]
-    assert len(dispatch) == cpu_run["calls"]
-    for _, lo, hi in dispatch:
-        inside = [s for s in cpu_run["program"] if lo <= s[1] and s[2] <= hi]
-        assert [s[0] for s in inside] == ["matmul.build"] + [
-            f"matmul.{s}" for s in PT.PHASES]
-        build, *cut = inside
-        assert build[2] <= cut[0][1]
-        assert all(a[2] == b[1] and a[1] < a[2] for a, b in zip(cut, cut[1:]))
+    assert len(dispatch) == cpu_run["counts"]["calls"]
+    builds = [s for s in cpu_run["program"] if s[0] == "matmul.build"]
+    assert not [b for b in builds for _, lo, hi in dispatch if lo <= b[1] and b[2] <= hi]
+    assert not [s for s in cpu_run["program"] if s[0].removeprefix("matmul.") in PT.PHASES]
+    assert cpu_run["readings"] == {
+        "entry_trace_ms": 0.0, "entry_lower_ms": 0.0, "entry_load_ms": 0.0,
+        "entry_launch_ms": 0.0, "entry_builds_per_step": 0.0, "unscoped_ms": None}
+    assert "skew_ms" not in cpu_run["metrics"]
 
 
 def test_without_a_chip_the_command_fails(tmp_path):
